@@ -31,6 +31,8 @@ import sys
 import time
 import traceback
 
+from repro.utils.compile_cache import enable_compile_cache
+
 BENCHES = [
     "fig06_stp_antt",      # main result: STP/ANTT L1..L10, 5 policies
     "fig07_utilization",   # utilization trace + makespan, L10 mix
@@ -52,6 +54,7 @@ BENCHES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--bench", nargs="*", default=None,
                     help="prefixes of benchmarks to run")

@@ -32,17 +32,6 @@ PYTHON="${PYTHON:-python}"
 CI_SMOKE_BENCHES="${CI_SMOKE_BENCHES-open_arrivals tpu_colocation}"
 START_S=$SECONDS
 
-# Deps: the image bakes in the jax/pallas toolchain; install only what's
-# missing. A dep that is neither installed nor installable fails the
-# gate loudly — tests can't run without it.
-for pkg in pytest numpy jax; do
-    if ! "$PYTHON" -c "import $pkg" >/dev/null 2>&1; then
-        echo "ci: installing missing dep: $pkg"
-        "$PYTHON" -m pip install -q "$pkg" || {
-            echo "ci: FAILED to import or install $pkg" >&2; exit 1; }
-    fi
-done
-
 MARK_ARGS=()
 if [ "${CI_FULL:-0}" = "1" ]; then
     MARK_ARGS=(-m "")               # include @slow tier-2 tests

@@ -1,5 +1,5 @@
 """qwen3-0.6b [dense] — 28L d_model=1024 16H (GQA kv=8) d_ff=3072
-vocab=151936; qk_norm, GQA, tied embeddings. [hf:Qwen/Qwen3-8B; hf]"""
+vocab=151936; qk_norm, GQA, tied embeddings. [hf:Qwen/Qwen3-0.6B; hf]"""
 from repro.configs.base import ModelConfig
 
 ARCH_ID = "qwen3-0.6b"

@@ -1,23 +1,18 @@
-"""Jitted wrapper for flash-decode, model layout in/out."""
+"""Wrapper for flash-decode, model layout in/out.
+
+Not jitted itself: it runs inside the model's jitted step, and resolving
+``interpret`` at call time keeps the compiled-or-interpreted decision
+out of any trace cache."""
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.decode_attention.kernel import decode_attention_fwd
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("window", "attn_softcap", "scale", "blk_k",
-                     "interpret"))
 def decode_attention(
     q: jnp.ndarray,        # [B, 1, Hq, D] (model layout)
     k_cache: jnp.ndarray,  # [B, S, Hkv, D]
@@ -33,7 +28,8 @@ def decode_attention(
     B, _, Hq, D = q.shape
     S = k_cache.shape[1]
     scale = D ** -0.5 if scale is None else scale
-    interpret = _interpret_default() if interpret is None else interpret
+    if interpret is None:
+        interpret = kernels.interpret_default()
     blk_k = min(blk_k, S)
     pad = (-S) % blk_k
     if pad:
@@ -42,7 +38,7 @@ def decode_attention(
     lens = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32) + 1, (B,))
     out = decode_attention_fwd(
-        jnp.moveaxis(q, 2, 1), k_cache, v_cache, lens, scale=scale,
+        q.reshape(B, Hq, D), k_cache, v_cache, lens, scale=scale,
         window=window, softcap=attn_softcap, blk_k=blk_k,
         interpret=interpret)
-    return jnp.moveaxis(out, 1, 2)
+    return out.reshape(B, 1, Hq, D)

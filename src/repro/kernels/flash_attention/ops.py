@@ -1,24 +1,16 @@
-"""Jitted wrapper: model layout [B, S, H, D] <-> kernel layout, padding,
-backend dispatch (compiled on TPU, interpret=True elsewhere)."""
+"""Wrapper: model layout [B, S, H, D] <-> kernel layout, padding,
+backend dispatch (compiled on TPU, interpret=True elsewhere; not jitted
+itself, for the reason given in ``decode_attention/ops.py``)."""
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "window", "attn_softcap", "scale",
-                     "blk_q", "blk_k", "interpret"))
 def flash_attention(
     q: jnp.ndarray,  # [B, S, Hq, D] (model layout)
     k: jnp.ndarray,  # [B, S, Hkv, D]
@@ -34,7 +26,8 @@ def flash_attention(
 ) -> jnp.ndarray:
     B, S, Hq, D = q.shape
     scale = D ** -0.5 if scale is None else scale
-    interpret = _interpret_default() if interpret is None else interpret
+    if interpret is None:
+        interpret = kernels.interpret_default()
     blk_q = min(blk_q, S)
     blk_k = min(blk_k, S)
     pad = (-S) % max(blk_q, blk_k)

@@ -1,22 +1,15 @@
-"""Jitted wrapper for paged flash-decode, model layout in/out."""
+"""Wrapper for paged flash-decode, model layout in/out (not jitted
+itself, for the reason given in ``decode_attention/ops.py``)."""
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.paged_attention.kernel import paged_attention_fwd
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("window", "attn_softcap", "scale", "interpret"))
 def paged_attention(
     q: jnp.ndarray,            # [B, 1, Hq, D] (model layout)
     k_pool: jnp.ndarray,       # [P, page, Hkv, D] shared page pool
@@ -31,10 +24,11 @@ def paged_attention(
 ) -> jnp.ndarray:
     B, _, Hq, D = q.shape
     scale = D ** -0.5 if scale is None else scale
-    interpret = _interpret_default() if interpret is None else interpret
+    if interpret is None:
+        interpret = kernels.interpret_default()
     lens = jnp.broadcast_to(jnp.asarray(lens, jnp.int32), (B,))
     out = paged_attention_fwd(
-        jnp.moveaxis(q, 2, 1), k_pool, v_pool, page_table, lens,
+        q.reshape(B, Hq, D), k_pool, v_pool, page_table, lens,
         scale=scale, window=window, softcap=attn_softcap,
         interpret=interpret)
-    return jnp.moveaxis(out, 1, 2)
+    return out.reshape(B, 1, Hq, D)

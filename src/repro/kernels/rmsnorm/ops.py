@@ -1,23 +1,19 @@
-"""Jitted wrapper: arbitrary leading dims, padding, dispatch."""
+"""Wrapper: arbitrary leading dims, padding, dispatch (not jitted
+itself, for the reason given in ``decode_attention/ops.py``)."""
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.rmsnorm.kernel import rmsnorm_fwd
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "blk", "interpret"))
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-6,
             blk: int = 256, interpret: Optional[bool] = None) -> jnp.ndarray:
-    interpret = _interpret_default() if interpret is None else interpret
+    if interpret is None:
+        interpret = kernels.interpret_default()
     shape = x.shape
     d = shape[-1]
     x2 = x.reshape(-1, d)

@@ -1,20 +1,15 @@
-"""Jitted wrapper: grouped B/C -> per-head, padding, dispatch."""
+"""Wrapper: grouped B/C -> per-head, padding, dispatch (not jitted
+itself, for the reason given in ``decode_attention/ops.py``)."""
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.ssd_scan.kernel import ssd_scan_fwd
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(
     xb: jnp.ndarray,      # [B, S, H, P]
     a: jnp.ndarray,       # [B, S, H]
@@ -25,7 +20,8 @@ def ssd_scan(
     initial_state: Optional[jnp.ndarray] = None,
     interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    interpret = _interpret_default() if interpret is None else interpret
+    if interpret is None:
+        interpret = kernels.interpret_default()
     B, S, H, P = xb.shape
     G = B_mat.shape[2]
     rep = H // G

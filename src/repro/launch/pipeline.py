@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.compat import shard_map
-
 
 def pipeline_apply(stage_fn: Callable, mesh, axis: str,
                    stage_params, x_micro: jnp.ndarray) -> jnp.ndarray:
@@ -72,7 +70,7 @@ def pipeline_apply(stage_fn: Callable, mesh, axis: str,
         out = jnp.where(rank == n_stages - 1, out, jnp.zeros_like(out))
         return jax.lax.psum(out, axis)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

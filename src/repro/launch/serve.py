@@ -70,6 +70,7 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
 import numpy as np
 
 from repro.configs import get_config
@@ -80,6 +81,7 @@ from repro.sched import (Autoscaler, ElasticController,
                          get_estimator, get_topology)
 from repro.serve import (Engine, JaxBackend, PagedJaxBackend, Request,
                          ServingDemand, pages_for)
+from repro.utils.compile_cache import enable_compile_cache
 
 #: estimators that make sense for a serving deployment (job-side ones
 #: like moe/oracle need an AppProfile target)
@@ -121,7 +123,12 @@ def parse_tenants(spec: str):
     return tenants
 
 
-def main():
+def main(argv=None, devices=None):
+    """Serve from the command line (or ``argv``); returns ``(engine,
+    summary)``.  Paged replica ``r`` runs on ``devices[r % len(devices)]``
+    (default: every device JAX sees, so four replicas on a four-chip
+    host hold one chip each)."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -240,7 +247,7 @@ def main():
                          "lifecycles; open at https://ui.perfetto.dev "
                          "or summarize with scripts/trace_report.py)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch, smoke=args.smoke)
     max_len = args.prompt_len + args.decode_steps + 1
@@ -322,10 +329,12 @@ def main():
         # pool sized so max_batch worst-case requests can reserve, +1
         # for the scratch page
         num_pages = 1 + args.max_batch * pages_for(max_len, page_size)
+        devices = devices or jax.devices()
         backends = [PagedJaxBackend(cfg, num_pages=num_pages,
                                     page_size=page_size,
                                     prefill_chunk=args.prefill_chunk,
-                                    seed=args.seed + r)
+                                    seed=args.seed + r, max_len=max_len,
+                                    device=devices[r % len(devices)])
                     for r in range(fleet)]
     else:
         backends = [JaxBackend(cfg, max_len=max_len, seed=args.seed + r)
@@ -394,8 +403,9 @@ def main():
               f"preemption risk")
     tot = summary["good_tokens"]
     print(f"served {summary['completed']} requests / {tot} tokens in "
-          f"{wall:.1f}s wall ({tot / max(wall, 1e-9):.1f} tok/s wall, "
-          f"{summary['goodput_tok_s']:.1f} tok/s virtual)")
+          f"{wall:.1f}s wall incl. compilation "
+          f"({tot / max(wall, 1e-9):.1f} tok/s wall; "
+          f"{summary['goodput_tok_s']:.1f} tok/s in modeled virtual time)")
     if tenancy is not None and summary["tenants"]:
         print(f"{'tenant':<12} {'weight':>6} {'credit':>6} "
               f"{'done':>6} {'goodput':>9} {'slo':>6} "
@@ -445,6 +455,7 @@ def main():
                       f"({info['family']}, conf="
                       f"{measured.confidence.get('net', 0.0):.2f}) vs "
                       f"declared {args.net_gbps_per_req:.3g}")
+    return engine, summary
 
 
 if __name__ == "__main__":

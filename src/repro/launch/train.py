@@ -28,9 +28,11 @@ from repro.launch import sharding as shd
 from repro.models import model as model_lib
 from repro.train import optim
 from repro.train.step import build_train_step
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -10,6 +10,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.models.layers import softcap
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -108,11 +109,18 @@ def paged_decode_attention(
     window: int = 0,
     attn_softcap: float = 0.0,
     scale: Optional[float] = None,
-    use_pallas: bool = False,
+    use_pallas: Optional[bool] = None,
     f32_logits: bool = True,
 ) -> jnp.ndarray:
     """One-token attention against a page-table KV pool; each row has its
-    own length (no shared position counter)."""
+    own length (no shared position counter).
+
+    ``use_pallas`` None runs the Pallas kernel wherever it compiles (a
+    TPU) and the XLA gather elsewhere; True / False force one of them
+    (the gather is the test oracle and the kernel's on-chip reference).
+    """
+    if use_pallas is None:
+        use_pallas = not kernels.interpret_default()
     if use_pallas:
         from repro.kernels.paged_attention import ops as pa_ops
         return pa_ops.paged_attention(

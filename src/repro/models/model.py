@@ -12,6 +12,7 @@ Design rules:
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -148,7 +149,10 @@ def abstract(cfg: ModelConfig) -> Params:
     return abstract_params(param_specs(cfg), cfg.param_dtype)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init(cfg: ModelConfig, rng) -> Params:
+    """Random weights, drawn in one program (eagerly, every leaf shape
+    compiles its own, which takes a minute on a TPU at full width)."""
     return init_params(param_specs(cfg), rng, cfg.param_dtype)
 
 
@@ -562,14 +566,18 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def init_paged_cache(cfg: ModelConfig, batch: int, num_pages: int,
-                     page_size: int, abstract_only: bool = False):
+                     page_size: int, abstract_only: bool = False,
+                     max_pages: Optional[int] = None):
     """Page-pool KV cache: a shared pool of fixed-size token pages plus a
     per-request page table and length.  Page 0 is the scratch page —
     unused table slots (and padding rows) point at it, so every gather
     hits a valid page and garbage writes land harmlessly.
 
     Layout: {"lens": [B], "table": [B, maxp], "k"/"v": [L, P, page, Hkv,
-    hd]} where maxp = num_pages - 1 upper-bounds any one request.
+    hd]} where maxp = ``max_pages``, the most pages any one request may
+    hold (default: the whole usable pool, num_pages - 1).  The decode
+    kernel prefetches the table into scalar memory, so its width is sized
+    by the longest request, not by the pool.
     """
     if cfg.family not in ("dense", "moe", "vlm") or cfg.local_global:
         raise NotImplementedError(
@@ -579,7 +587,7 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_pages: int,
     mk = (jax.ShapeDtypeStruct if abstract_only
           else lambda s, d: jnp.zeros(s, d))
     L, hd, Hkv = cfg.num_layers, cfg.head_dim, cfg.num_kv_heads
-    maxp = max(num_pages - 1, 1)
+    maxp = max_pages or max(num_pages - 1, 1)
     return {
         "lens": mk((batch,), jnp.int32),
         "table": mk((batch, maxp), jnp.int32),
@@ -602,7 +610,7 @@ def _paged_kv_write(pool, new, table, positions, page_size):
 
 def _paged_attn_block(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                       pools, table, write_table, positions, kv_lens, *,
-                      chunk_attend: bool):
+                      chunk_attend: bool, use_pallas: Optional[bool]):
     """Pre-norm attention with residual over the page pool.
 
     x: [B, S, d]; positions: [B, S] absolute positions of these tokens;
@@ -610,7 +618,9 @@ def _paged_attn_block(p: Params, cfg: ModelConfig, x: jnp.ndarray,
     through ``write_table`` (inactive rows' tables are zeroed there, so
     their writes land on the scratch page); gathers use the real
     ``table``.  With ``chunk_attend`` the S chunk tokens attend causally
-    through the gathered pages (prefill chunks); otherwise S == 1 decode.
+    through the gathered pages (prefill chunks); otherwise S == 1 decode,
+    whose attention ``use_pallas`` picks (None: the kernel wherever it
+    compiles — see :func:`paged_decode_attention`).
     """
     from repro.kernels.paged_attention.ref import gather_pages
     from repro.models.layers import apply_rope
@@ -641,7 +651,7 @@ def _paged_attn_block(p: Params, cfg: ModelConfig, x: jnp.ndarray,
         out = paged_decode_attention(
             q, kp, vp, table, kv_lens,
             attn_softcap=cfg.attn_softcap, scale=_attn_scale(cfg),
-            use_pallas=cfg.use_pallas, f32_logits=cfg.attn_f32_logits)
+            use_pallas=use_pallas, f32_logits=cfg.attn_f32_logits)
     out = jnp.einsum("bsk,kd->bsd",
                      out.reshape(B, S, cfg.num_heads * hd), p["wo"])
     if cfg.use_post_norm:
@@ -650,7 +660,7 @@ def _paged_attn_block(p: Params, cfg: ModelConfig, x: jnp.ndarray,
 
 
 def _paged_stack(params, cfg, x, cache, positions, kv_lens, active, *,
-                 chunk_attend: bool):
+                 chunk_attend: bool, use_pallas: Optional[bool] = None):
     """Dense/moe/vlm stack over the page pool; pools ride scan xs just
     like the dense cache's [L, B, ...] arrays ride theirs."""
     table = cache["table"]
@@ -664,7 +674,7 @@ def _paged_stack(params, cfg, x, cache, positions, kv_lens, active, *,
         pb, pools = xs
         h, npools = _paged_attn_block(
             pb["attn"], cfg, h, pools, table, write_table, positions,
-            kv_lens, chunk_attend=chunk_attend)
+            kv_lens, chunk_attend=chunk_attend, use_pallas=use_pallas)
         if "moe" in pb:
             h, _ = moe_block(pb["moe"], cfg, h, pb.get("shared_mlp"))
         else:
@@ -677,16 +687,20 @@ def _paged_stack(params, cfg, x, cache, positions, kv_lens, active, *,
 
 
 def decode_step_paged(params: Params, cfg: ModelConfig, cache,
-                      token: jnp.ndarray, active=None):
+                      token: jnp.ndarray, active=None,
+                      use_pallas: Optional[bool] = None):
     """One-token decode over the paged cache; every row is at its own
     position ``lens[b]``.  token: [B, 1] int32; active: optional [B]
     bool — inactive rows (mid-prefill / padding) write to the scratch
     page, keep their length, and produce garbage logits callers must
-    not read.  Returns (logits [B, 1, V], updated cache)."""
+    not read; use_pallas: the attention path (None: the paged kernel
+    where it compiles, the XLA gather elsewhere).  Returns (logits
+    [B, 1, V], updated cache)."""
     x = _embed(params, cfg, token)
     positions = cache["lens"][:, None]          # [B, 1]
     h, nc = _paged_stack(params, cfg, x, cache, positions,
-                         cache["lens"] + 1, active, chunk_attend=False)
+                         cache["lens"] + 1, active, chunk_attend=False,
+                         use_pallas=use_pallas)
     nl = cache["lens"] + 1
     if active is not None:
         nl = jnp.where(jnp.asarray(active, bool), nl, cache["lens"])

@@ -29,7 +29,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import activation
 from repro.models.moe import MoEOutput, load_balance_loss, router_topk
-from repro.utils.compat import shard_map
 
 _ctx = threading.local()
 
@@ -148,7 +147,7 @@ def moe_ffn_ep(
         return y.astype(xl.dtype), aux, dropped
 
     n_spec = P(token_axes if len(token_axes) > 1 else token_axes[0], None)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(n_spec, P(None, None), P(daxis, None, maxis),
                   P(daxis, None, maxis), P(daxis, maxis, None)),

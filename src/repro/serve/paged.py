@@ -443,23 +443,37 @@ class PagedJaxBackend(_PagedScheduler, Backend):
     freed rows are reused (no compaction gathers).  Host mirrors of the
     page tables and lengths are authoritative; the device cache's
     ``table``/``lens`` entries are rebuilt from them before every call.
+
+    ``max_len`` bounds prompt + new tokens of any one request (default:
+    the whole usable pool); the page table is that many pages wide,
+    which keeps it small enough for the decode kernel's scalar memory
+    at deployment pool sizes.  ``device`` pins params, cache and every
+    per-call input to one device (default: JAX's default device), so
+    replicas can each hold their own chip.
     """
 
     join_stride = 1
 
     def __init__(self, cfg, params=None, num_pages: int = 64,
                  page_size: int = 16, prefill_chunk: int = 32,
-                 seed: int = 0, step_time: Optional[SimBackend] = None):
+                 seed: int = 0, step_time: Optional[SimBackend] = None,
+                 max_len: Optional[int] = None, device=None):
         import jax
         from repro.models import model as model_lib
         from repro.train.step import (build_paged_decode_step,
                                       build_prefill_chunk_step)
         super().__init__(num_pages, page_size, prefill_chunk,
                          step_time or SimBackend())
+        self._maxp = self.alloc.usable_pages
+        if max_len is not None:
+            self._maxp = min(pages_for(max_len, page_size), self._maxp)
+            self.max_len = self._maxp * self.page_size
         self._jax = jax
+        self.device = device
         self.cfg = cfg
-        self.params = params if params is not None \
-            else model_lib.init(cfg, jax.random.key(seed))
+        self.params = self._on_device(
+            lambda: params if params is not None
+            else model_lib.init(cfg, jax.random.key(seed)))
         self._model_lib = model_lib
         self._decode = jax.jit(build_paged_decode_step(cfg),
                                donate_argnums=(1,))
@@ -471,16 +485,23 @@ class PagedJaxBackend(_PagedScheduler, Backend):
         self._rows: Dict[int, int] = {}     # rid -> row index
         self._row_free: List[int] = []
         self._last: Dict[int, int] = {}     # rid -> last sampled token
-        self._maxp = self.alloc.usable_pages
         self._table_np = np.zeros((0, self._maxp), np.int32)
+
+    def _on_device(self, make):
+        """Build a pytree on this backend's device (never staging it on
+        the default one) and commit it there."""
+        with self._jax.default_device(self.device):
+            return self._jax.device_put(make(), self.device)
 
     # --- row / cache management -------------------------------------------
     def _ensure_capacity(self, extra_rows: int) -> None:
         need = len(self._rows) + extra_rows
         cap = max(_bucket(need), self._cap)
         if self._cache is None:
-            self._cache = self._model_lib.init_paged_cache(
-                self.cfg, cap, self.alloc.num_pages, self.page_size)
+            self._cache = self._on_device(
+                lambda: self._model_lib.init_paged_cache(
+                    self.cfg, cap, self.alloc.num_pages, self.page_size,
+                    max_pages=self._maxp))
         if cap > self._cap:
             self._row_free.extend(range(self._cap, cap))
             pad = np.zeros((cap - self._cap, self._maxp), np.int32)
@@ -518,10 +539,12 @@ class PagedJaxBackend(_PagedScheduler, Backend):
             lens[row] = self._live_tokens(r)
         return lens
 
+    def _put(self, x: np.ndarray):
+        return self._jax.device_put(x, self.device)
+
     def _push_cache(self, lens: np.ndarray) -> None:
-        import jax.numpy as jnp
-        self._cache["table"] = jnp.asarray(self._table_np)
-        self._cache["lens"] = jnp.asarray(lens)
+        self._cache["table"] = self._put(self._table_np)
+        self._cache["lens"] = self._put(lens)
 
     # --- compute hooks ----------------------------------------------------
     def _prefill_rows(self, work) -> List[int]:
@@ -543,9 +566,8 @@ class PagedJaxBackend(_PagedScheduler, Backend):
             lens[self._rows[r.rid]] = s
         self._push_cache(lens)
         logits, self._cache = self._chunk(
-            self.params, self._cache, jnp.asarray(tokens),
-            jnp.asarray(start), jnp.asarray(chunk_lens),
-            jnp.asarray(active))
+            self.params, self._cache, self._put(tokens),
+            self._put(start), self._put(chunk_lens), self._put(active))
         toks = np.asarray(jnp.argmax(logits, -1)[:, 0])
         return [int(toks[self._rows[r.rid]]) for r, _, _ in work]
 
@@ -564,8 +586,7 @@ class PagedJaxBackend(_PagedScheduler, Backend):
             lens[self._rows[r.rid]] = r.context_len - 1
         self._push_cache(lens)
         logits, self._cache = self._decode(
-            self.params, self._cache, jnp.asarray(token),
-            jnp.asarray(active))
+            self.params, self._cache, self._put(token), self._put(active))
         toks = np.asarray(jnp.argmax(logits, -1)[:, 0])
         for r in decoding:
             r.tokens.append(int(toks[self._rows[r.rid]]))

@@ -6,7 +6,7 @@ the real drivers alike.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -106,13 +106,16 @@ def build_decode_step(cfg: ModelConfig) -> Callable:
     return decode_step
 
 
-def build_paged_decode_step(cfg: ModelConfig) -> Callable:
+def build_paged_decode_step(cfg: ModelConfig,
+                            use_pallas: Optional[bool] = None) -> Callable:
     """One-token decode over the page-pool cache; per-row positions.
+    ``use_pallas`` None (served) runs the paged kernel wherever it
+    compiles; False forces the XLA gather reference.
 
     (params, cache, token [B,1], active [B] bool) -> (logits, cache)."""
     def paged_decode_step(params, cache, token, active):
         return model_lib.decode_step_paged(params, cfg, cache, token,
-                                           active)
+                                           active, use_pallas=use_pallas)
     return paged_decode_step
 
 

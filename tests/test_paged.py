@@ -333,6 +333,37 @@ def test_paged_jax_matches_dense_jax_token_streams():
     assert paged == dense
 
 
+def test_paged_jax_table_sized_by_longest_request():
+    """The page table is as wide as the longest request the backend
+    accepts, not the pool (the decode kernel keeps it in scalar memory),
+    and the narrow table serves the pool-wide table's greedy streams."""
+    from repro.serve import PagedJaxBackend
+    cfg = _smoke_cfg()
+    rng = np.random.default_rng(3)
+    lens = rng.integers(6, 21, 4)
+    prompts = [list(rng.integers(3, cfg.vocab_size, n)) for n in lens]
+    demand = ServingDemand(weights_gb=0.01, kv_gb_per_token=1e-6)
+
+    def run(be):
+        reqs = [Request(rid=i, prompt_len=len(p), max_new_tokens=4,
+                        arrival=0.0, prompt=list(p))
+                for i, p in enumerate(prompts)]
+        eng = Engine(reqs, demand, ResourceVector(hbm=100.0), be,
+                     max_batch=4)
+        assert eng.run()["completed"] == 4
+        return {r.rid: list(r.tokens) for r in eng.requests}
+
+    num_pages = 1 + 4 * pages_for(24, 4)
+    wide = PagedJaxBackend(cfg, num_pages=num_pages, page_size=4,
+                           prefill_chunk=8, seed=0)
+    narrow = PagedJaxBackend(cfg, num_pages=num_pages, page_size=4,
+                             prefill_chunk=8, seed=0, max_len=24)
+    assert run(narrow) == run(wide)
+    assert narrow.max_len == 24
+    assert narrow._cache["table"].shape[1] == pages_for(24, 4)
+    assert wide._cache["table"].shape[1] == num_pages - 1
+
+
 @pytest.mark.slow
 def test_paged_jax_preemption_and_staggered_arrivals():
     """Tight budget on the real paged backend: mid-stream joins at

@@ -303,6 +303,7 @@ def _scan(body, carry, xs, cfg, mode):
 # Forward passes per family
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def _embed(params, cfg, tokens):
     x = params["embed"][tokens]  # gather [B,S,d]
     if getattr(cfg, "embed_scale", False) or cfg.local_global:
@@ -310,6 +311,7 @@ def _embed(params, cfg, tokens):
     return x
 
 
+@jax.named_scope("head")
 def _unembed(params, cfg, h):
     """Final norm + LM head (+ gemma2 final softcap). h: [..., d]."""
     h = rms_norm(h, params["final_ln_w"], cfg.norm_eps)
@@ -626,36 +628,39 @@ def _paged_attn_block(p: Params, cfg: ModelConfig, x: jnp.ndarray,
     from repro.models.layers import apply_rope
     B, S, _ = x.shape
     hd = cfg.head_dim
-    h = rms_norm(x, p["ln_w"], cfg.norm_eps, use_pallas=False)
-    q = jnp.einsum("bsd,dk->bsk", h, p["wq"]).reshape(
-        B, S, cfg.num_heads, hd)
-    k = jnp.einsum("bsd,dk->bsk", h, p["wk"]).reshape(
-        B, S, cfg.num_kv_heads, hd)
-    v = jnp.einsum("bsd,dk->bsk", h, p["wv"]).reshape(
-        B, S, cfg.num_kv_heads, hd)
-    q, k = _qk_normed(p, cfg, q, k)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        h = rms_norm(x, p["ln_w"], cfg.norm_eps, use_pallas=False)
+        q = jnp.einsum("bsd,dk->bsk", h, p["wq"]).reshape(
+            B, S, cfg.num_heads, hd)
+        k = jnp.einsum("bsd,dk->bsk", h, p["wk"]).reshape(
+            B, S, cfg.num_kv_heads, hd)
+        v = jnp.einsum("bsd,dk->bsk", h, p["wv"]).reshape(
+            B, S, cfg.num_kv_heads, hd)
+        q, k = _qk_normed(p, cfg, q, k)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     page = pools[0].shape[1]
-    kp = _paged_kv_write(pools[0], k, write_table, positions, page)
-    vp = _paged_kv_write(pools[1], v, write_table, positions, page)
-    if chunk_attend:
-        kd = gather_pages(kp, table)           # [B, maxp*page, Hkv, hd]
-        vd = gather_pages(vp, table)
-        out = attention(
-            q, kd, vd, causal=True, q_positions=positions,
-            k_positions=jnp.arange(kd.shape[1]), kv_len=kv_lens,
-            attn_softcap=cfg.attn_softcap, scale=_attn_scale(cfg),
-            use_pallas=False, f32_logits=cfg.attn_f32_logits)
-    else:
-        out = paged_decode_attention(
-            q, kp, vp, table, kv_lens,
-            attn_softcap=cfg.attn_softcap, scale=_attn_scale(cfg),
-            use_pallas=use_pallas, f32_logits=cfg.attn_f32_logits)
-    out = jnp.einsum("bsk,kd->bsd",
-                     out.reshape(B, S, cfg.num_heads * hd), p["wo"])
-    if cfg.use_post_norm:
-        out = rms_norm(out, p["post_ln_w"], cfg.norm_eps)
+    with jax.named_scope("kv_write"):
+        kp = _paged_kv_write(pools[0], k, write_table, positions, page)
+        vp = _paged_kv_write(pools[1], v, write_table, positions, page)
+    with jax.named_scope("attention"):
+        if chunk_attend:
+            kd = gather_pages(kp, table)       # [B, maxp*page, Hkv, hd]
+            vd = gather_pages(vp, table)
+            out = attention(
+                q, kd, vd, causal=True, q_positions=positions,
+                k_positions=jnp.arange(kd.shape[1]), kv_len=kv_lens,
+                attn_softcap=cfg.attn_softcap, scale=_attn_scale(cfg),
+                use_pallas=False, f32_logits=cfg.attn_f32_logits)
+        else:
+            out = paged_decode_attention(
+                q, kp, vp, table, kv_lens,
+                attn_softcap=cfg.attn_softcap, scale=_attn_scale(cfg),
+                use_pallas=use_pallas, f32_logits=cfg.attn_f32_logits)
+        out = jnp.einsum("bsk,kd->bsd",
+                         out.reshape(B, S, cfg.num_heads * hd), p["wo"])
+        if cfg.use_post_norm:
+            out = rms_norm(out, p["post_ln_w"], cfg.norm_eps)
     return x + out, (kp, vp)
 
 
@@ -675,10 +680,11 @@ def _paged_stack(params, cfg, x, cache, positions, kv_lens, active, *,
         h, npools = _paged_attn_block(
             pb["attn"], cfg, h, pools, table, write_table, positions,
             kv_lens, chunk_attend=chunk_attend, use_pallas=use_pallas)
-        if "moe" in pb:
-            h, _ = moe_block(pb["moe"], cfg, h, pb.get("shared_mlp"))
-        else:
-            h = mlp_block(pb["mlp"], cfg, h)
+        with jax.named_scope("mlp"):
+            if "moe" in pb:
+                h, _ = moe_block(pb["moe"], cfg, h, pb.get("shared_mlp"))
+            else:
+                h = mlp_block(pb["mlp"], cfg, h)
         return h, npools
 
     xs = (params["blocks"], (cache["k"], cache["v"]))

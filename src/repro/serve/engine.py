@@ -53,7 +53,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Sequence, Union
 
 from repro.core.experts import MemoryFunction
-from repro.obs.telemetry import sample_node
+from repro.obs.spans import span
 from repro.sched.admission import AdmissionController
 from repro.sched.cluster import ClusterRuntime, ClusterState, Node, Router
 from repro.sched.elastic import Autoscaler, pick_spawn_node
@@ -476,6 +476,10 @@ class Engine:
             if fresh:
                 dt += self.backends[ridx].join(fresh, now)
             for r in joined:
+                if r.admissions == 0:
+                    self.telemetry.inc("serve.admitted")
+                    self.telemetry.inc("serve.admission_wait_s",
+                                       now - r.arrival)
                 r.admissions += 1
                 r.state = RequestState.RUNNING
                 if self.tracer is not None:
@@ -704,24 +708,46 @@ class Engine:
             ridx = payload
         if ridx in self._failed:
             return False  # failed replica: chain dies; repair re-pushes
-        self._route_released(t)
         running = self._running[ridx]
-        cands = self._candidates_for(ridx, t)
-        if not running and not cands:
-            nxt = self.queue.next_arrival()
-            if nxt is None:
-                if self._pending[ridx]:
-                    # pending exists but nothing can join (should be
-                    # impossible: empty batch accepts any valid request)
-                    raise RuntimeError("serving deadlock: pending "
-                                       "requests but no candidates")
-                return False  # replica idle for good: chain ends
-            self._push_step(nxt, ridx)
-            return False      # idle wake, not a planned step
-        plan = self.batchers[ridx].plan_step(running, cands, t,
-                                             self._step_no)
-        dt_join = self._apply(plan, ridx, t)
-        dt_decode = self.backends[ridx].decode(running)
+        nxt = self.queue.next_arrival()
+        if not running and not self._pending[ridx] \
+                and (nxt is None or nxt > t + 1e-12):
+            return self._idle_wake(ridx)   # nothing to route or run
+        tm = self.telemetry
+        with span("serve.step", admitted=tm.counter("serve.admitted"),
+                  admission_wait_s=tm.counter("serve.admission_wait_s")):
+            with span("serve.plan"):
+                self._route_released(t)
+                cands = self._candidates_for(ridx, t)
+                if not running and not cands:
+                    # the released requests went to other replicas
+                    return self._idle_wake(ridx)
+                plan = self.batchers[ridx].plan_step(running, cands, t,
+                                                     self._step_no)
+            dt_join = self._apply(plan, ridx, t)
+            dt_decode = self.backends[ridx].decode(running)
+            with span("serve.retire"):
+                self._finish_step(plan, ridx, t, dt_join, dt_decode)
+
+    def _idle_wake(self, ridx: int) -> bool:
+        """An idle replica waits for the next arrival, or its chain
+        ends when none is left."""
+        nxt = self.queue.next_arrival()
+        if nxt is None:
+            if self._pending[ridx]:
+                # pending exists but nothing can join (should be
+                # impossible: empty batch accepts any valid request)
+                raise RuntimeError("serving deadlock: pending "
+                                   "requests but no candidates")
+            return False  # replica idle for good: chain ends
+        self._push_step(nxt, ridx)
+        return False      # idle wake, not a planned step
+
+    def _finish_step(self, plan: StepDecision, ridx: int, t: float,
+                     dt_join: float, dt_decode: float) -> None:
+        """Stamp, retire, reconcile and record a planned step, then
+        schedule the replica's next one."""
+        running = self._running[ridx]
         shrunk = self.batchers[ridx].shrunk
         if shrunk:
             # a decode step is lockstep across the batch: the slowest
@@ -806,7 +832,6 @@ class Engine:
         tr.counter(f"node{ridx}:util", t_end,
                    {axis: node.utilization(axis)
                     for axis in node.capacity.axes}, process=proc)
-        sample_node(self.telemetry, node, t_end)
 
     def _run_continuous(self) -> float:
         self.runtime.on("step", self._on_step)

@@ -40,6 +40,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.obs.spans import span
 from repro.serve.backends import (PAD_ID, Backend, SimBackend, _bucket,
                                   _shrink_bucket)
 from repro.serve.request import Request
@@ -547,47 +548,69 @@ class PagedJaxBackend(_PagedScheduler, Backend):
         self._cache["lens"] = self._put(lens)
 
     # --- compute hooks ----------------------------------------------------
+    def _call_stats(self, rows: int, tokens: int) -> dict:
+        """A device call's span stats: its rows and tokens, and the page
+        pool (pages holding KV, reserved by admission, usable)."""
+        return dict(rows=rows, tokens=tokens,
+                    pages=self.alloc.allocated_pages,
+                    reserved=self.alloc.reserved_pages,
+                    pool=self.alloc.usable_pages)
+
     def _prefill_rows(self, work) -> List[int]:
         import jax.numpy as jnp
-        C = self.prefill_chunk
-        tokens = np.full((self._cap, C), PAD_ID, np.int32)
-        start = np.zeros((self._cap,), np.int32)
-        chunk_lens = np.zeros((self._cap,), np.int32)
-        active = np.zeros((self._cap,), bool)
-        for r, s, cl in work:
-            row = self._rows[r.rid]
-            seq = list(r.prompt) + list(r.tokens)    # recompute view
-            tokens[row, :cl] = seq[s:s + cl]
-            start[row], chunk_lens[row], active[row] = s, cl, True
-        lens = self._sync_tables()
-        # mid-chunk rows carry their pre-chunk progress; grow_to already
-        # covered the chunk's pages, so the device tables are current
-        for r, s, cl in work:
-            lens[self._rows[r.rid]] = s
-        self._push_cache(lens)
-        logits, self._cache = self._chunk(
-            self.params, self._cache, self._put(tokens),
-            self._put(start), self._put(chunk_lens), self._put(active))
-        toks = np.asarray(jnp.argmax(logits, -1)[:, 0])
+        with span("serve.prefill_call", **self._call_stats(
+                len(work), sum(cl for _, _, cl in work))):
+            with span("serve.inputs"):
+                C = self.prefill_chunk
+                tokens = np.full((self._cap, C), PAD_ID, np.int32)
+                start = np.zeros((self._cap,), np.int32)
+                chunk_lens = np.zeros((self._cap,), np.int32)
+                active = np.zeros((self._cap,), bool)
+                for r, s, cl in work:
+                    row = self._rows[r.rid]
+                    seq = list(r.prompt) + list(r.tokens)  # recompute view
+                    tokens[row, :cl] = seq[s:s + cl]
+                    start[row], chunk_lens[row], active[row] = s, cl, True
+                lens = self._sync_tables()
+                # mid-chunk rows carry their pre-chunk progress; grow_to
+                # already covered the chunk's pages, so the device tables
+                # are current
+                for r, s, cl in work:
+                    lens[self._rows[r.rid]] = s
+                self._push_cache(lens)
+                args = [self._put(x)
+                        for x in (tokens, start, chunk_lens, active)]
+            with span("serve.dispatch"):
+                logits, self._cache = self._chunk(self.params, self._cache,
+                                                  *args)
+            with span("serve.readback"):
+                toks = np.asarray(jnp.argmax(logits, -1)[:, 0])
         return [int(toks[self._rows[r.rid]]) for r, _, _ in work]
 
     def _decode_rows(self, decoding: Sequence[Request]) -> float:
         import jax.numpy as jnp
-        token = np.full((self._cap, 1), PAD_ID, np.int32)
-        active = np.zeros((self._cap,), bool)
-        for r in decoding:
-            row = self._rows[r.rid]
-            token[row, 0] = r.tokens[-1]
-            active[row] = True
-        lens = self._sync_tables()
-        # the decode step writes the input token's KV at position len
-        # and attends len + 1 entries: pass len EXCLUDING that token
-        for r in decoding:
-            lens[self._rows[r.rid]] = r.context_len - 1
-        self._push_cache(lens)
-        logits, self._cache = self._decode(
-            self.params, self._cache, self._put(token), self._put(active))
-        toks = np.asarray(jnp.argmax(logits, -1)[:, 0])
+        with span("serve.decode_call", **self._call_stats(
+                len(decoding), len(decoding))):
+            with span("serve.inputs"):
+                token = np.full((self._cap, 1), PAD_ID, np.int32)
+                active = np.zeros((self._cap,), bool)
+                for r in decoding:
+                    row = self._rows[r.rid]
+                    token[row, 0] = r.tokens[-1]
+                    active[row] = True
+                lens = self._sync_tables()
+                # the decode step writes the input token's KV at position
+                # len and attends len + 1 entries: pass len EXCLUDING that
+                # token
+                for r in decoding:
+                    lens[self._rows[r.rid]] = r.context_len - 1
+                self._push_cache(lens)
+                args = [self._put(token), self._put(active)]
+            with span("serve.dispatch"):
+                logits, self._cache = self._decode(self.params, self._cache,
+                                                   *args)
+            with span("serve.readback"):
+                toks = np.asarray(jnp.argmax(logits, -1)[:, 0])
         for r in decoding:
             r.tokens.append(int(toks[self._rows[r.rid]]))
         return self._timer.step_cost(len(decoding))

@@ -8,7 +8,9 @@
   SAME summary dict as the untraced run, bit for bit (the acceptance
   bar that lets tracing ride every run without a goldens fork);
 * ``Telemetry`` on the runtime — per-kind event counters, stale drops,
-  node-utilization timelines;
+  the engine's first admissions and their waits;
+* ``span`` — the served path's wall-clock ``serve.*`` spans: inert and
+  nestable with no profiler session;
 * structured admission rejects + decision provenance;
 * per-link utilization ledgers (``Topology.link_stats``) and the
   rejected-join axis counters in ``ServingMetrics``;
@@ -23,7 +25,8 @@ import pytest
 from repro.core import (MoEPredictor, SimConfig, Simulator,
                         spark_sim_suite, training_apps)
 from repro.core.simulator import OursPolicy
-from repro.obs import NullTracer, Telemetry, Tracer, validate_chrome_trace
+from repro.obs import (NullTracer, Telemetry, Tracer, span,
+                       validate_chrome_trace)
 from repro.obs.report import summarize
 from repro.sched import ClusterRuntime, ClusterState
 from repro.sched.admission import AdmissionController
@@ -231,25 +234,73 @@ def test_runtime_counts_events_and_stale_drops():
     assert s["counters"]["events.ev"] == 3
 
 
-def test_engine_samples_node_utilization_timelines():
+def test_telemetry_summary_holds_counters_and_gauges():
+    tm = Telemetry()
+    tm.inc("x")
+    tm.inc("x", 2.0)
+    tm.set_gauge("g", 3)
+    assert tm.summary() == {"counters": {"x": 3.0}, "gauges": {"g": 3.0}}
+
+
+def _first_joins(tracer):
+    """rid -> virtual seconds of its first ``join`` instant."""
+    joins = {}
+    for e in tracer.chrome()["traceEvents"]:
+        if e["ph"] == "i" and e["name"] == "join":
+            joins.setdefault(e["args"]["rid"], e["ts"] / 1e6)
+    return joins
+
+
+def test_engine_counts_first_admissions_and_their_waits():
+    """``serve.admitted`` counts each request once, at its first join,
+    and ``serve.admission_wait_s`` adds its wait from arrival."""
     tracer = Tracer()
     eng = _reference_engine(tracer=tracer)
     eng.run()
-    lines = eng.telemetry.timelines
-    assert any(k.startswith("node0.util.") for k in lines)
-    for pts in lines.values():
-        ts = [t for t, _ in pts]
-        assert ts == sorted(ts)             # virtual-time ordered
-        # forced over-budget progress can push booked/capacity past 1
-        assert all(v >= 0.0 and np.isfinite(v) for _, v in pts)
+    tm = eng.telemetry
+    joins = _first_joins(tracer)
+    assert tm.counter("serve.admitted") == len(joins) == len(eng.requests)
+    want = sum(joins[r.rid] - r.arrival for r in eng.requests)
+    assert tm.counter("serve.admission_wait_s") == pytest.approx(
+        want, abs=1e-9)
+    assert want > 0.0                       # the budget made some wait
 
 
-def test_telemetry_summary_reduces_timelines():
-    tm = Telemetry()
-    tm.sample("x", 0.0, 1.0)
-    tm.sample("x", 1.0, 3.0)
-    s = tm.summary()["timelines"]["x"]
-    assert s == {"n": 2, "mean": 2.0, "max": 3.0, "last": 3.0}
+def test_admission_wait_matches_the_reports_queueing():
+    """Requests that all arrive at once are routed at their arrival, so
+    the wait the engine counts equals the routed -> first-join queueing
+    ``obs.report.summarize`` rebuilds from the trace alone."""
+    demand = ServingDemand(weights_gb=0.5, kv_gb_per_token=2e-4)
+    budget = ResourceVector(hbm=0.5 + 2e-4 * 72 * 3.0)
+    reqs = [Request(rid=r.rid, prompt_len=r.prompt_len,
+                    max_new_tokens=r.max_new_tokens, arrival=0.0)
+            for r in make_requests(12, seed=4)]
+    tracer = Tracer()
+    eng = Engine(reqs, demand, budget, backend=SimBackend(),
+                 mode="continuous", placement="fcfs", max_batch=16,
+                 tracer=tracer)
+    eng.run()
+    rep = summarize(tracer.chrome())
+    assert eng.telemetry.counter("serve.admitted") == 12
+    assert eng.telemetry.counter("serve.admission_wait_s") == pytest.approx(
+        rep["breakdown"]["queueing_s"], rel=1e-9)
+    assert rep["breakdown"]["queueing_s"] > 0.0
+
+
+# --- wall-clock spans -------------------------------------------------------
+
+def test_span_is_inert_without_a_profiler_and_nests():
+    order = []
+    with span("serve.step", admitted=3, admission_wait_s=0.5):
+        order.append("step")
+        with span("serve.plan"):
+            order.append("plan")
+        with span("serve.retire"):
+            order.append("retire")
+    assert order == ["step", "plan", "retire"]
+    with pytest.raises(KeyError):           # exceptions pass through
+        with span("serve.step"):
+            raise KeyError("x")
 
 
 # --- structured admission rejects + provenance ------------------------------

@@ -423,3 +423,92 @@ def test_jax_dense_cache_shape_hysteresis():
     # cap 8 holds through 2 removals (streak < patience), shrinks on
     # the 3rd, then holds again
     assert caps == [8, 8, 8, 2, 2]
+
+
+# --- what the served path names in a profiler trace -------------------------
+
+SCOPES = {"embed", "qkv", "kv_write", "attention", "mlp", "head"}
+
+
+def _within(inner, outer):
+    return outer.start_ns <= inner.start_ns \
+        and inner.start_ns + inner.duration_ns \
+        <= outer.start_ns + outer.duration_ns
+
+
+def test_paged_jax_serve_records_program_spans(tmp_path):
+    """Under ``jax.profiler.trace`` a served run records the engine's
+    and the backend's ``serve.*`` spans with their stats, nested as
+    step > plan and call > inputs / dispatch / readback."""
+    import jax
+    from repro.serve import PagedJaxBackend
+    cfg = _smoke_cfg()
+    reqs = [Request(rid=i, prompt_len=6, max_new_tokens=3, arrival=0.0,
+                    prompt=[3 + i] * 6) for i in range(2)]
+    be = PagedJaxBackend(cfg, num_pages=9, page_size=4, prefill_chunk=4,
+                         seed=0)
+    eng = Engine(reqs, ServingDemand(weights_gb=0.01, kv_gb_per_token=1e-6),
+                 ResourceVector(hbm=100.0), be, max_batch=2)
+    with jax.profiler.trace(str(tmp_path)):
+        assert eng.run()["completed"] == 2
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    ev = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("serve."):
+                    ev.setdefault(e.name, []).append(e)
+    assert set(ev) == {"serve.step", "serve.plan", "serve.retire",
+                       "serve.prefill_call", "serve.decode_call",
+                       "serve.inputs", "serve.dispatch", "serve.readback"}
+    steps = ev["serve.step"]
+    assert len(steps) == len(ev["serve.plan"]) == len(ev["serve.retire"])
+    for child in ev["serve.plan"] + ev["serve.retire"]:
+        assert any(_within(child, s) for s in steps)
+    last = dict(max(steps, key=lambda e: e.start_ns).stats)
+    assert last["admitted"] == 2 and last["admission_wait_s"] >= 0.0
+    # two 6-token prompts in chunks of 4: two chunk calls of 2 rows
+    chunks = ev["serve.prefill_call"]
+    assert [dict(c.stats)["tokens"] for c in chunks] == [8, 4]
+    for call in chunks + ev["serve.decode_call"]:
+        st = dict(call.stats)
+        assert st["rows"] == 2 and st["pool"] == 8
+        assert 0 < st["pages"] <= st["reserved"] <= st["pool"]
+        phases = [next(e for e in ev[name] if _within(e, call))
+                  for name in ("serve.inputs", "serve.dispatch",
+                               "serve.readback")]
+        assert [p.start_ns for p in phases] == sorted(
+            p.start_ns for p in phases)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_paged_step_programs_name_their_scopes(program):
+    """Each step program's lowering names the model step's six scopes;
+    the decode program names the paged kernel too."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from repro.models import model as model_lib
+    from repro.train.step import (build_paged_decode_step,
+                                  build_prefill_chunk_step)
+    cfg = _smoke_cfg()
+    params = model_lib.abstract(cfg)
+    cache = model_lib.init_paged_cache(cfg, 2, 9, 4, abstract_only=True,
+                                       max_pages=4)
+    rows = jax.ShapeDtypeStruct((2,), jnp.int32)
+    active = jax.ShapeDtypeStruct((2,), jnp.bool_)
+    if program == "decode":
+        fn = build_paged_decode_step(cfg, use_pallas=True)
+        args = (jax.ShapeDtypeStruct((2, 1), jnp.int32), active)
+    else:
+        fn = build_prefill_chunk_step(cfg)
+        args = (jax.ShapeDtypeStruct((2, 4), jnp.int32), rows, rows,
+                active)
+    text = jax.jit(fn).lower(params, cache, *args).as_text(debug_info=True)
+    # name-stack locations, not source-file ones
+    names = {part for loc in re.findall(r'loc\("([^"]*)"', text)
+             if not loc.endswith(".py") for part in loc.split("/")}
+    assert SCOPES <= names
+    assert ("paged_attention" in names) == (program == "decode")

@@ -83,6 +83,25 @@ def test_paged_attention_compiles(one_chip, compiled_kernels, batch,
         S((batch, max_pages), I32), S((batch,), I32)))
 
 
+def test_paged_attention_kernel_carries_its_name(one_chip,
+                                                 compiled_kernels):
+    """The compiled kernel's op is named ``paged_attention`` in its
+    scope path, which is how a device trace finds it."""
+    import re
+    from repro.kernels.paged_attention.ops import paged_attention
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = S((281, 16, 8, 128), BF16)
+    text = _compile(paged_attention, S((8, 1, 16, 128), BF16), pool, pool,
+                    S((8, 35), I32), S((8,), I32)).as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    assert re.search(r'op_name="[^"]*/paged_attention/pallas_call"',
+                     calls[0])
+
+
 def test_rmsnorm_compiles(one_chip, compiled_kernels):
     from repro.kernels.rmsnorm.ops import rmsnorm
     _assert_kernel_fits(_compile(

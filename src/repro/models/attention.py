@@ -137,6 +137,50 @@ def paged_decode_attention(
         scale=scale, use_pallas=False, f32_logits=f32_logits)
 
 
+def paged_prefill_attention(
+    q: jnp.ndarray,            # [B, C, Hq, D]
+    k_pool: jnp.ndarray,       # [P, page, Hkv, D] shared page pool
+    v_pool: jnp.ndarray,
+    page_table: jnp.ndarray,   # [B, maxp] int32 (unused slots -> page 0)
+    start: jnp.ndarray,        # [B] int32: position of chunk token 0
+    kv_lens: jnp.ndarray,      # [B] int32: valid tokens after the chunk
+    *,
+    window: int = 0,
+    attn_softcap: float = 0.0,
+    scale: Optional[float] = None,
+    use_pallas: Optional[bool] = None,
+    f32_logits: bool = True,
+) -> jnp.ndarray:
+    """Causal attention of a chunk of C tokens per row, at positions
+    ``start + 0..C-1``, over the row's first ``kv_lens`` pooled tokens.
+
+    ``use_pallas`` None runs the Pallas kernel wherever it compiles (a
+    TPU, at widths whose pages tile) and the XLA gather elsewhere; True /
+    False force one of them (the gather is the test oracle and the
+    kernel's on-chip reference).
+    """
+    from repro.kernels.paged_attention import kernel as pa_kernel
+    if use_pallas is None:
+        use_pallas = not kernels.interpret_default() and \
+            pa_kernel.prefill_tiles(k_pool)
+    if use_pallas:
+        return pa_kernel.paged_prefill_attention_fwd(
+            q, k_pool, v_pool, page_table, start, kv_lens,
+            scale=q.shape[-1] ** -0.5 if scale is None else scale,
+            window=window, softcap=attn_softcap,
+            interpret=kernels.interpret_default())
+    from repro.kernels.paged_attention.ref import gather_pages
+    k = gather_pages(k_pool, page_table)       # [B, maxp*page, Hkv, D]
+    v = gather_pages(v_pool, page_table)
+    start = jnp.asarray(start, jnp.int32)
+    return attention(
+        q, k, v, causal=True,
+        q_positions=start[:, None] + jnp.arange(q.shape[1])[None, :],
+        k_positions=jnp.arange(k.shape[1]), kv_len=kv_lens, window=window,
+        attn_softcap=attn_softcap, scale=scale, use_pallas=False,
+        f32_logits=f32_logits)
+
+
 def decode_attention(
     q: jnp.ndarray,            # [B, 1, Hq, D]
     k_cache: jnp.ndarray,      # [B, S, Hkv, D]

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import ssm as ssm_mod
 from repro.models.attention import (attention, decode_attention,
-                                    paged_decode_attention)
+                                    paged_decode_attention,
+                                    paged_prefill_attention)
 from repro.models.layers import mlp, rms_norm, softcap
 from repro.models.moe import moe_ffn
 from repro.models.params import P, abstract_params, init_params
@@ -618,13 +619,13 @@ def _paged_attn_block(p: Params, cfg: ModelConfig, x: jnp.ndarray,
     x: [B, S, d]; positions: [B, S] absolute positions of these tokens;
     kv_lens: [B] total valid tokens after this write.  KV writes route
     through ``write_table`` (inactive rows' tables are zeroed there, so
-    their writes land on the scratch page); gathers use the real
+    their writes land on the scratch page); reads use the real
     ``table``.  With ``chunk_attend`` the S chunk tokens attend causally
-    through the gathered pages (prefill chunks); otherwise S == 1 decode,
-    whose attention ``use_pallas`` picks (None: the kernel wherever it
-    compiles — see :func:`paged_decode_attention`).
+    through the row's pages (prefill chunks); otherwise S == 1 decode.
+    ``use_pallas`` picks either attention (None: the kernel wherever it
+    compiles — see :func:`paged_prefill_attention` and
+    :func:`paged_decode_attention`).
     """
-    from repro.kernels.paged_attention.ref import gather_pages
     from repro.models.layers import apply_rope
     B, S, _ = x.shape
     hd = cfg.head_dim
@@ -645,13 +646,10 @@ def _paged_attn_block(p: Params, cfg: ModelConfig, x: jnp.ndarray,
         vp = _paged_kv_write(pools[1], v, write_table, positions, page)
     with jax.named_scope("attention"):
         if chunk_attend:
-            kd = gather_pages(kp, table)       # [B, maxp*page, Hkv, hd]
-            vd = gather_pages(vp, table)
-            out = attention(
-                q, kd, vd, causal=True, q_positions=positions,
-                k_positions=jnp.arange(kd.shape[1]), kv_len=kv_lens,
+            out = paged_prefill_attention(
+                q, kp, vp, table, positions[:, 0], kv_lens,
                 attn_softcap=cfg.attn_softcap, scale=_attn_scale(cfg),
-                use_pallas=False, f32_logits=cfg.attn_f32_logits)
+                use_pallas=use_pallas, f32_logits=cfg.attn_f32_logits)
         else:
             out = paged_decode_attention(
                 q, kp, vp, table, kv_lens,
@@ -716,7 +714,8 @@ def decode_step_paged(params: Params, cfg: ModelConfig, cache,
 
 def prefill_chunk(params: Params, cfg: ModelConfig, cache,
                   tokens: jnp.ndarray, start: jnp.ndarray,
-                  chunk_lens: jnp.ndarray, active=None):
+                  chunk_lens: jnp.ndarray, active=None,
+                  use_pallas: Optional[bool] = None):
     """Process one prompt chunk per row, writing KV into the rows' pages.
 
     tokens: [B, C] int32 (PAD-filled past each row's chunk); start: [B]
@@ -725,7 +724,9 @@ def prefill_chunk(params: Params, cfg: ModelConfig, cache,
     chunks PAD-fill the tail — those writes land beyond the row's
     length inside its own pages, masked now and overwritten by the next
     chunk or decode); active: optional [B] bool — inactive rows
-    (decoding / idle) write to the scratch page and keep their length.
+    (decoding / idle) write to the scratch page and keep their length;
+    use_pallas: the attention path (None: the paged prefill kernel where
+    it compiles, the XLA gather elsewhere).
     Returns (logits at each row's last valid chunk token [B, 1, V],
     cache with lens = start + chunk_lens for active rows).
     """
@@ -735,7 +736,8 @@ def prefill_chunk(params: Params, cfg: ModelConfig, cache,
     chunk_lens = jnp.asarray(chunk_lens, jnp.int32)
     positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     h, nc = _paged_stack(params, cfg, x, cache, positions,
-                         start + chunk_lens, active, chunk_attend=True)
+                         start + chunk_lens, active, chunk_attend=True,
+                         use_pallas=use_pallas)
     nl = start + chunk_lens
     if active is not None:
         nl = jnp.where(jnp.asarray(active, bool), nl, cache["lens"])

@@ -558,8 +558,11 @@ class PagedJaxBackend(_PagedScheduler, Backend):
 
     def _prefill_rows(self, work) -> List[int]:
         import jax.numpy as jnp
-        with span("serve.prefill_call", **self._call_stats(
-                len(work), sum(cl for _, _, cl in work))):
+        # kv_tokens: the K/V the chunk attends, start + chunk per row
+        with span("serve.prefill_call",
+                  kv_tokens=sum(s + cl for _, s, cl in work),
+                  **self._call_stats(len(work),
+                                     sum(cl for _, _, cl in work))):
             with span("serve.inputs"):
                 C = self.prefill_chunk
                 tokens = np.full((self._cap, C), PAD_ID, np.int32)

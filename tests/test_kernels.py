@@ -300,3 +300,75 @@ def test_paged_attention_matches_dense_gather_path():
     a = paged_decode_attention(q, kp, vp, table, ln, use_pallas=True)
     b = paged_decode_attention(q, kp, vp, table, ln, use_pallas=False)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+def _prefill_case(Hq, Hkv, starts, chunk_lens, *, C=8, page=4, D=16,
+                  P=48, dtype=jnp.float32, seed=0):
+    """A chunk of C queries per row at ``starts``, ``chunk_lens`` of them
+    valid (0: an inactive row, start 0), over a shuffled pool; each
+    table is one slot wider than its row needs, the spare slots parked
+    on scratch page 0."""
+    r = np.random.default_rng(seed)
+    B = len(starts)
+    q = jnp.asarray(r.normal(0, 1, (B, C, Hq, D)), dtype)
+    kp = jnp.asarray(r.normal(0, 1, (P, page, Hkv, D)), dtype)
+    vp = jnp.asarray(r.normal(0, 1, (P, page, Hkv, D)), dtype)
+    kv_lens = [s + cl for s, cl in zip(starts, chunk_lens)]
+    table = np.zeros((B, -(-max(kv_lens) // page) + 1), np.int32)
+    perm = list(r.permutation(np.arange(1, P)))
+    for b, ln in enumerate(kv_lens):
+        for i in range(-(-ln // page)):
+            table[b, i] = perm.pop()
+    return (q, kp, vp, jnp.asarray(table), jnp.asarray(starts, jnp.int32),
+            jnp.asarray(kv_lens, jnp.int32))
+
+
+@pytest.mark.parametrize("Hq,Hkv,starts,chunk_lens,window,softcap,block,dtype", [
+    # rows at different starts, chunks across page boundaries, a short
+    # final chunk, an inactive row; blocks of two pages
+    (4, 2, [0, 13, 0, 22], [8, 5, 0, 8], 0, 0.0, 8, jnp.float32),
+    (10, 2, [4, 21, 0], [8, 3, 0], 0, 0.0, 8, jnp.float32),   # G = 5
+    (4, 2, [20, 3, 0], [8, 6, 0], 6, 0.0, 8, jnp.float32),    # window
+    (4, 2, [9, 0], [7, 8], 0, 20.0, 8, jnp.float32),          # softcap
+    (10, 2, [27, 0, 5], [8, 0, 2], 0, 0.0, 512, jnp.bfloat16),  # one block
+], ids=["starts-g2", "g5", "window", "softcap", "bf16-one-block"])
+def test_paged_prefill_kernel_matches_gather_path(Hq, Hkv, starts, chunk_lens,
+                                                  window, softcap, block,
+                                                  dtype):
+    """The chunked-prefill kernel (interpret mode, KV blocks of
+    ``block`` tokens) agrees with the gather + dense ``attention`` path
+    on every valid query of every active row; inactive rows' outputs
+    are garbage on both paths and not compared."""
+    from repro.kernels.paged_attention.kernel import (
+        paged_prefill_attention_fwd)
+    from repro.models.attention import paged_prefill_attention
+    q, kp, vp, table, start, kv_lens = _prefill_case(
+        Hq, Hkv, starts, chunk_lens, dtype=dtype)
+    D = q.shape[-1]
+    out = paged_prefill_attention_fwd(
+        q, kp, vp, table, start, kv_lens, scale=D ** -0.5, window=window,
+        softcap=softcap, block_tokens=block, interpret=True)
+    ref = paged_prefill_attention(q, kp, vp, table, start, kv_lens,
+                                  window=window, attn_softcap=softcap,
+                                  use_pallas=False)
+    assert out.shape == ref.shape == q.shape
+    for b, cl in enumerate(chunk_lens):
+        np.testing.assert_allclose(
+            np.asarray(out[b, :cl], np.float32),
+            np.asarray(ref[b, :cl], np.float32),
+            atol=_tol(dtype), rtol=_tol(dtype))
+
+
+@pytest.mark.parametrize("page,hkv,dtype,tiles", [
+    (16, 8, jnp.bfloat16, True),     # qwen3 widths, the served page
+    (8, 8, jnp.bfloat16, False),     # half a bf16 sublane tile
+    (8, 8, jnp.float32, True),       # a whole f32 sublane tile
+    (16, 1, jnp.bfloat16, False),    # Hkv*D of 16 lanes: a test config
+], ids=["bf16-page16", "bf16-page8", "f32-page8", "narrow-heads"])
+def test_prefill_kernel_taken_only_where_pages_tile(page, hkv, dtype, tiles):
+    """The chunk path picks the prefill kernel only for pools whose page
+    copies Mosaic tiles; elsewhere it keeps the XLA gather."""
+    from repro.kernels.paged_attention.kernel import prefill_tiles
+    D = 128 if hkv > 1 else 16
+    pool = jax.ShapeDtypeStruct((4, page, hkv, D), dtype)
+    assert prefill_tiles(pool) is tiles

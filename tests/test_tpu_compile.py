@@ -159,19 +159,33 @@ def _full_width_step(one_chip, step, *inputs):
     return compiled
 
 
+def _kernel_calls(compiled) -> int:
+    return sum('custom_call_target="tpu_custom_call"' in ln
+               for ln in compiled.as_text().splitlines())
+
+
 def test_full_width_paged_decode_step_compiles(one_chip, compiled_kernels):
-    """The served decode step holds the paged kernel, compiled."""
+    """The served decode step holds the paged kernel, compiled, and no
+    other: a device trace finds the decode kernel as the decode
+    program's one ``tpu_custom_call``."""
     from repro.train.step import build_paged_decode_step
     compiled = _full_width_step(
         one_chip, build_paged_decode_step,
         jax.ShapeDtypeStruct((8, 1), I32),
         jax.ShapeDtypeStruct((8,), jnp.bool_))
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernel_calls(compiled) == 1
 
 
-def test_full_width_prefill_chunk_step_fits(one_chip):
+def test_full_width_prefill_chunk_step_fits(one_chip, compiled_kernels):
+    """The served chunk step holds the paged prefill kernel, compiled,
+    and no array as wide as a row's whole table (35 pages of 16 tokens:
+    the gather path's dense K/V copy and its logits are 560 wide)."""
+    import re
     from repro.train.step import build_prefill_chunk_step
     row = jax.ShapeDtypeStruct((8,), I32)
-    _full_width_step(one_chip, build_prefill_chunk_step,
-                     jax.ShapeDtypeStruct((8, 32), I32), row, row,
-                     jax.ShapeDtypeStruct((8,), jnp.bool_))
+    compiled = _full_width_step(
+        one_chip, build_prefill_chunk_step,
+        jax.ShapeDtypeStruct((8, 32), I32), row, row,
+        jax.ShapeDtypeStruct((8,), jnp.bool_))
+    assert _kernel_calls(compiled) == 1
+    assert not re.search(r"\[[0-9,]*\b560\b", compiled.as_text())
